@@ -198,6 +198,8 @@ def main() -> None:
         sys.exit(1 if check_baselines(args.drift_ref) else 0)
     if args.profile_out:
         args.profile = True
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
 
     whatif_knobs = None
     if args.whatif is not None:

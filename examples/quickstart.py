@@ -16,9 +16,11 @@ import numpy as np               # noqa: E402
 
 from repro.core import (ClientRuntime, DeviceSpec, LinkSpec,  # noqa: E402
                         ServerSpec)
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     # -- a phone on WiFi driving two edge servers on a fast LAN ----------
     rt = ClientRuntime(
         servers=[ServerSpec("edge0", [DeviceSpec("gpu", flops=13e12)]),

@@ -21,6 +21,7 @@ from repro.configs import RunOverrides              # noqa: E402
 from repro.optim import AdamW, cosine_schedule      # noqa: E402
 from repro.training.loop import LoopConfig, Trainer  # noqa: E402
 from repro.training.step import make_train_step     # noqa: E402
+from repro.utils import enable_compile_cache         # noqa: E402
 
 
 def model_100m() -> ModelConfig:
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = model_100m()
     run = RunOverrides()

@@ -1,29 +1,43 @@
 """D2Q9 lattice-Boltzmann (BGK) in JAX — the FluidX3D case-study payload
-(paper §7.2) at laptop scale.
+(paper §7.2).
 
-Supports domain decomposition along x with explicit halo exchange, so the
-multi-node benchmark runs the *real* kernel per sub-domain while the
-PoCL-R runtime moves the boundary buffers (implicit migration — the
-"idiomatic OpenCL" mode the paper added to FluidX3D).
+The lattice is decomposed along x into halo-padded slabs. ``run_offloaded``
+is the CFD offload loop: the client writes the slabs to its servers,
+each server steps its slab with the real kernel on its own device, and
+the halos are exchanged on the host-side buffers between steps.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+import os
+import time
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import ClientRuntime, DeviceSpec, LinkSpec, ServerSpec
+
 # D2Q9 velocities and weights
 C = np.array([[0, 0], [1, 0], [0, 1], [-1, 0], [0, -1],
               [1, 1], [-1, 1], [-1, -1], [1, -1]])
 W = np.array([4 / 9] + [1 / 9] * 4 + [1 / 36] * 4)
-OPP = np.array([0, 3, 4, 1, 2, 7, 8, 5, 6])
+
+# At DEFAULT precision the TPU rounds the whole f32 distribution to bf16
+# before these contractions (a ~2^-9 relative error in u); HIGHEST keeps f32.
+_F32 = jax.lax.Precision.HIGHEST
+
+# the paper's CFD testbed (§7.2): 1 GbE to the client, 100 GbE between servers
+_CLIENT_LINK = LinkSpec(latency=50e-6, bandwidth=1e9 / 8)
+_PEER_LINK = LinkSpec(latency=10e-6, bandwidth=100e9 / 8)
 
 
 def equilibrium(rho: jax.Array, u: jax.Array) -> jax.Array:
     """rho [H,W], u [2,H,W] → feq [9,H,W]."""
-    cu = jnp.einsum("qd,dhw->qhw", jnp.asarray(C, u.dtype), u)
+    cu = jnp.einsum("qd,dhw->qhw", jnp.asarray(C, u.dtype), u,
+                    precision=_F32)
     usq = jnp.sum(u * u, axis=0)
     w = jnp.asarray(W, u.dtype)[:, None, None]
     return w * rho * (1 + 3 * cu + 4.5 * cu ** 2 - 1.5 * usq)
@@ -31,8 +45,8 @@ def equilibrium(rho: jax.Array, u: jax.Array) -> jax.Array:
 
 def macroscopic(f: jax.Array):
     rho = jnp.sum(f, axis=0)
-    u = jnp.einsum("qd,qhw->dhw", jnp.asarray(C, f.dtype), f) / \
-        jnp.maximum(rho, 1e-12)
+    u = jnp.einsum("qd,qhw->dhw", jnp.asarray(C, f.dtype), f,
+                   precision=_F32) / jnp.maximum(rho, 1e-12)
     return rho, u
 
 
@@ -59,26 +73,54 @@ def init_shear(H: int, W_: int, dtype=jnp.float32) -> jax.Array:
     return equilibrium(rho, u)
 
 
+def reference_steps(f: np.ndarray, steps: int,
+                    tau: float = 0.6) -> np.ndarray:
+    """Plain float64 numpy D2Q9 BGK with periodic boundaries: the
+    independent reference ``lbm_step`` is checked against."""
+    f = np.array(f, dtype=np.float64)
+    for _ in range(steps):
+        rho = f.sum(axis=0)
+        ux = (f[1] + f[5] + f[8] - f[3] - f[6] - f[7]) / rho
+        uy = (f[2] + f[5] + f[6] - f[4] - f[7] - f[8]) / rho
+        usq = ux * ux + uy * uy
+        out = np.empty_like(f)
+        for q, ((cx, cy), w) in enumerate(zip(C, W)):
+            cu = cx * ux + cy * uy
+            feq = w * rho * (1 + 3 * cu + 4.5 * cu * cu - 1.5 * usq)
+            out[q] = np.roll(f[q] + (feq - f[q]) / tau, (cy, cx),
+                             axis=(0, 1))
+        f = out
+    return f
+
+
+def reference_max_error(got: np.ndarray, f0: np.ndarray, steps: int,
+                        band: int = 256) -> float:
+    """max|got - reference_steps(f0, steps)|, computed in row bands on
+    all host cores so a full-size lattice fits in host memory. After
+    ``steps`` steps a row depends only on the ``steps`` rows either side,
+    so each band is stepped with that margin and its interior compared."""
+    H = f0.shape[1]
+
+    def band_error(lo: int) -> float:
+        hi = min(lo + band, H)
+        rows = np.arange(lo - steps, hi + steps) % H
+        ref = reference_steps(f0[:, rows], steps)[:, steps:-steps or None]
+        return float(np.abs(got[:, lo:hi] - ref).max())
+
+    with ThreadPoolExecutor(len(os.sched_getaffinity(0))) as ex:
+        return max(ex.map(band_error, range(0, H, band)))
+
+
 # ---------------- domain decomposition ----------------
 
-def split_domain(f: jax.Array, n: int) -> list:
+def split_domain(f: np.ndarray, n: int) -> list:
     """Split [9,H,W] along W into n slabs, each padded with 1-col halos."""
     W_ = f.shape[2]
-    assert W_ % n == 0
+    if W_ % n:
+        raise ValueError(f"width {W_} does not split into {n} slabs")
     w = W_ // n
-    slabs = []
-    for i in range(n):
-        lo = (i * w - 1) % W_
-        core = f[:, :, i * w:(i + 1) * w]
-        left = f[:, :, lo:lo + 1]
-        right = f[:, :, ((i + 1) * w) % W_:((i + 1) * w) % W_ + 1]
-        slabs.append(jnp.concatenate([left, core, right], axis=2))
-    return slabs
-
-
-def slab_step(slab: jax.Array, tau: float = 0.6) -> jax.Array:
-    """Step a halo-padded slab; interior columns are valid afterwards."""
-    return lbm_step(slab, tau)
+    cols = np.arange(-1, w + 1)
+    return [np.take(f, (i * w + cols) % W_, axis=2) for i in range(n)]
 
 
 def exchange_halos(slabs: list) -> list:
@@ -89,13 +131,61 @@ def exchange_halos(slabs: list) -> list:
         left_src = slabs[(i - 1) % n][:, :, -2:-1]   # its last interior col
         right_src = slabs[(i + 1) % n][:, :, 1:2]    # its first interior col
         core = slabs[i][:, :, 1:-1]
-        out.append(jnp.concatenate([left_src, core, right_src], axis=2))
+        out.append(np.concatenate([left_src, core, right_src], axis=2))
     return out
 
 
-def run_decomposed(f0: jax.Array, n: int, steps: int, tau: float = 0.6):
-    slabs = split_domain(f0, n)
-    for _ in range(steps):
-        slabs = [slab_step(s, tau) for s in slabs]
-        slabs = exchange_halos(slabs)
-    return jnp.concatenate([s[:, :, 1:-1] for s in slabs], axis=2)
+@dataclasses.dataclass
+class OffloadRun:
+    f: np.ndarray          # [9,H,W] lattice after the last step
+    devices: list          # per server: devices its kernel outputs came from
+    step_seconds: list     # host wall s per step, ended by the host copy
+    stats: dict            # ClientRuntime.stats(): simulated clock
+
+
+def run_offloaded(f0, n_servers: int, steps: int) -> OffloadRun:
+    """Step ``f0`` through ``ClientRuntime`` on ``n_servers`` servers.
+
+    Server ``i`` computes on ``jax.local_devices()[i % count]``: its slab
+    is committed there before the jitted ``lbm_step``, after which the
+    slab's interior columns are valid. Each step enqueues the slab kernels
+    and reads, waits for them, exchanges halos between the host-side
+    buffers and writes the slabs back."""
+    local = jax.local_devices()
+    devs = [local[i % len(local)] for i in range(n_servers)]
+    rt = ClientRuntime(
+        servers=[ServerSpec(f"s{i}", [DeviceSpec(d.device_kind)])
+                 for i, d in enumerate(devs)],
+        client_link=_CLIENT_LINK, peer_link=_PEER_LINK, transport="tcp")
+    seen = [set() for _ in devs]
+
+    def kernel(i):
+        def run(slab):
+            out = lbm_step(jax.device_put(slab, devs[i]))
+            seen[i].update(out.devices())
+            return np.asarray(out)
+        return run
+
+    slabs = split_domain(np.asarray(f0), n_servers)
+    bufs = [rt.create_buffer(int(s.nbytes)) for s in slabs]
+    evs = [rt.enqueue_write(f"s{i}", b, s)
+           for i, (b, s) in enumerate(zip(bufs, slabs))]
+    step_seconds = []
+    for step in range(steps):
+        t0 = time.perf_counter()
+        for i, b in enumerate(bufs):
+            # simulated device time: one read and one write of the slab
+            k = rt.enqueue_kernel(f"s{i}", fn=kernel(i), inputs=[b],
+                                  outputs=[b], bytes_moved=2 * b.nbytes,
+                                  wait_for=[evs[i]], name="lbm_step")
+            rt.enqueue_read(f"s{i}", b, wait_for=[k])
+        rt.finish()
+        slabs = [b.data for b in bufs]
+        if step < steps - 1:
+            evs = [rt.enqueue_write(f"s{i}", b, s) for i, (b, s) in
+                   enumerate(zip(bufs, exchange_halos(slabs)))]
+        step_seconds.append(time.perf_counter() - t0)
+    f = np.concatenate([s[:, :, 1:-1] for s in slabs], axis=2)
+    return OffloadRun(f=f, devices=[sorted(s, key=lambda d: d.id)
+                                    for s in seen],
+                      step_seconds=step_seconds, stats=rt.stats())

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.topk_compress.ref import topk_pack_ref, unpack_ref
-from repro.utils import jax_axis_size, jax_shard_map
 
 Pytree = Any
 
@@ -34,7 +33,7 @@ def compressed_psum_leaf(g: jax.Array, err: jax.Array, *, axis: str,
     """One leaf: top-k pack → all-gather(axis) → sum of unpacked payloads.
 
     Returns (g_synced, new_err). Mean over the axis is applied."""
-    n_pods = jax_axis_size(axis)
+    n_pods = jax.lax.axis_size(axis)
     shape = g.shape
     n = int(np.prod(shape))
     npad = _round_block(n, block)
@@ -81,5 +80,5 @@ def pod_manual_shard_map(fn, mesh, in_specs, out_specs):
     Note: partial-manual shard_map requires check_vma (the default); with
     check_vma=False jax treats the region as fully manual."""
     manual = frozenset({"pod"}) & frozenset(mesh.axis_names)
-    return jax_shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, axis_names=manual)

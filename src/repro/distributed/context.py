@@ -16,8 +16,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils import jax_typeof
-
 # Logical activation/param axis → mesh axes (tuple). Tuned per run.
 DEFAULT_RULES = {
     # params
@@ -145,7 +143,7 @@ def shard_act(x: jax.Array, names: Sequence) -> jax.Array:
     ctx = current()
     if ctx is None:
         return x
-    vma = frozenset(getattr(jax_typeof(x), "vma", None) or frozenset())
+    vma = jax.typeof(x).vma
     if vma:
         # inside a partial-manual region: skip the constraint — mixing
         # Manual-typed mesh constraints with the outer Auto mesh tickles

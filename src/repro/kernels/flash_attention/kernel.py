@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.utils import pallas_tpu_compiler_params
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -145,7 +143,7 @@ def flash_attention_fwd(
             pltpu.VMEM((q_chunk, LANES), jnp.float32),   # running denom
             pltpu.VMEM((q_chunk, hd), jnp.float32),      # output acc
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention_fwd",

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.utils import pallas_tpu_compiler_params
-
 
 def _ssd_kernel(x_ref, dA_ref, b_ref, c_ref, y_ref, fin_ref, state_ref, *,
                 n_chunks: int):
@@ -99,7 +97,7 @@ def ssd_scan(x, dA, Bm, Cm, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((BH, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="ssd_scan",

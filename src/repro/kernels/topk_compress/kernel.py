@@ -17,8 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.utils import pallas_tpu_compiler_params
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _topk_kernel(x_ref, vals_ref, idx_ref, resid_ref, *, k: int):
@@ -70,7 +69,7 @@ def topk_pack(x: jax.Array, k_per_block: int, block: int = 1024,
             jax.ShapeDtypeStruct((nb, k_per_block), jnp.int32),
             jax.ShapeDtypeStruct((nb, block), x.dtype),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="topk_pack",

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.optim.adamw import AdamW, TrainState
-from repro.utils import (grad_cast, jax_shard_map, storage_barrier,
+from repro.utils import (grad_cast, storage_barrier,
                          tree_add, tree_scale, tree_zeros_like, vma_like)
 
 AUX_LOSS_COEF = 0.01
@@ -165,7 +165,7 @@ def make_compressed_train_step(cfg: ModelConfig, optimizer: AdamW, mesh,
         err = jax.tree.map(lambda e: e[None], err)
         return state, err, metrics
 
-    return jax_shard_map(
+    return jax.shard_map(
         pod_body, mesh=mesh,
         in_specs=(P("pod"), P(None, "pod"), P("pod")),
         out_specs=(P("pod"), P("pod"), P()),

@@ -11,71 +11,20 @@ import numpy as np
 Pytree = Any
 
 
-def jax_typeof(x):
-    """Version-compat shim for ``jax.typeof`` (added in jax 0.6).
-
-    Older installs (0.4.x) fall back to the abstract value, which carries
-    the same shape/dtype info; extension attributes like ``vma`` are read
-    with ``getattr`` defaults at the call sites either way."""
-    fn = getattr(jax, "typeof", None)
-    if fn is not None:
-        return fn(x)
-    return jax.core.get_aval(x)
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def jax_shard_map(fn, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None):
-    """Version-compat shim for ``jax.shard_map`` (top-level in jax 0.6).
-
-    0.4.x only has ``jax.experimental.shard_map.shard_map``, expresses
-    partial-manual regions through ``auto=`` (the complement of the new
-    API's ``axis_names=``), and calls ``check_vma`` ``check_rep``. The
-    0.4.x replication checker does not understand partial-manual
-    regions, so it is disabled whenever ``auto`` is non-empty."""
-    sm = getattr(jax, "shard_map", None)
-    kw = {}
-    if sm is not None:
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-            kw["check_rep"] = False   # overrides check_vma: the 0.4.x
-            # replication checker cannot handle partial-manual regions
-    mapped = sm_old(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kw)
-    # 0.4.x supports partial-manual only under jit (the eager impl raises
-    # NotImplementedError for non-empty ``auto``); jitting is a no-op for
-    # callers that already jit
-    return jax.jit(mapped) if auto else mapped
-
-
-def jax_axis_size(axis):
-    """Version-compat shim for ``jax.lax.axis_size`` (jax 0.6): older
-    installs count participants with a unit psum over the axis."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return jax.lax.psum(1, axis)
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """Version-compat shim: ``pltpu.CompilerParams`` was named
-    ``TPUCompilerParams`` before jax 0.6. Imported lazily so utils stays
-    light for non-kernel users."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile. ``$JAX_COMPILATION_CACHE_DIR``, which JAX reads itself, wins
+    when set; otherwise the cache lives at ``<repo>/.jax_cache``. The path
+    is part of the cache key, so it is fixed. Returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def storage_barrier(x: Pytree) -> Pytree:
@@ -165,12 +114,12 @@ def vma_like(x: Pytree, template) -> Pytree:
     data is pod-'varying'; the VMA checker rejects the mismatch. This
     promotes x when (and only when) the template is varying, and is a
     no-op outside shard_map."""
-    vma = getattr(jax_typeof(template), "vma", None) or frozenset()
+    vma = jax.typeof(template).vma
     if not vma:
         return x
 
     def promote(a):
-        have = getattr(jax_typeof(a), "vma", None) or frozenset()
+        have = jax.typeof(a).vma
         need = tuple(sorted(vma - have))
         return jax.lax.pcast(a, need, to="varying") if need else a
 

@@ -123,9 +123,8 @@ def test_compressed_psum_error_feedback():
     import functools
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils import jax_shard_map
 
-    @functools.partial(jax_shard_map, mesh=mesh, in_specs=(P(), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
                        out_specs=(P(), P()), check_vma=False)
     def step(g, e):
         return compressed_psum_tree(g, e, axis="pod", k_per_block=32,
