@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ClientRuntime, DeviceSpec, LinkSpec, ServerSpec
+from repro.core.trace import span
 
 # D2Q9 velocities and weights
 C = np.array([[0, 0], [1, 0], [0, 1], [-1, 0], [0, -1],
@@ -120,18 +121,20 @@ def split_domain(f: np.ndarray, n: int) -> list:
         raise ValueError(f"width {W_} does not split into {n} slabs")
     w = W_ // n
     cols = np.arange(-1, w + 1)
-    return [np.take(f, (i * w + cols) % W_, axis=2) for i in range(n)]
+    with span("lbm.split"):
+        return [np.take(f, (i * w + cols) % W_, axis=2) for i in range(n)]
 
 
 def exchange_halos(slabs: list) -> list:
     """Copy boundary columns between neighbours (periodic)."""
     n = len(slabs)
     out = []
-    for i in range(n):
-        left_src = slabs[(i - 1) % n][:, :, -2:-1]   # its last interior col
-        right_src = slabs[(i + 1) % n][:, :, 1:2]    # its first interior col
-        core = slabs[i][:, :, 1:-1]
-        out.append(np.concatenate([left_src, core, right_src], axis=2))
+    with span("lbm.exchange_halos"):
+        for i in range(n):
+            left_src = slabs[(i - 1) % n][:, :, -2:-1]   # last interior col
+            right_src = slabs[(i + 1) % n][:, :, 1:2]    # first interior col
+            core = slabs[i][:, :, 1:-1]
+            out.append(np.concatenate([left_src, core, right_src], axis=2))
     return out
 
 
@@ -150,42 +153,53 @@ def run_offloaded(f0, n_servers: int, steps: int) -> OffloadRun:
     is committed there before the jitted ``lbm_step``, after which the
     slab's interior columns are valid. Each step enqueues the slab kernels
     and reads, waits for them, exchanges halos between the host-side
-    buffers and writes the slabs back."""
+    buffers and writes the slabs back.
+
+    The host's phases are spans (``core.trace.span``): ``lbm.split``
+    and ``lbm.exchange_halos`` (in their functions), ``lbm.h2d`` and
+    ``lbm.d2h`` (each slab's copy to its device and back, with its
+    ``server`` and ``bytes``) and ``lbm.concatenate``."""
     local = jax.local_devices()
     devs = [local[i % len(local)] for i in range(n_servers)]
+    names = [f"s{i}" for i in range(n_servers)]
     rt = ClientRuntime(
-        servers=[ServerSpec(f"s{i}", [DeviceSpec(d.device_kind)])
-                 for i, d in enumerate(devs)],
+        servers=[ServerSpec(name, [DeviceSpec(d.device_kind)])
+                 for name, d in zip(names, devs)],
         client_link=_CLIENT_LINK, peer_link=_PEER_LINK, transport="tcp")
     seen = [set() for _ in devs]
 
     def kernel(i):
         def run(slab):
-            out = lbm_step(jax.device_put(slab, devs[i]))
+            with span("lbm.h2d", server=names[i], bytes=slab.nbytes):
+                x = jax.device_put(slab, devs[i])
+            out = lbm_step(x)
+            del x       # the slab's device copy goes once its step is queued
             seen[i].update(out.devices())
-            return np.asarray(out)
+            with span("lbm.d2h", server=names[i], bytes=out.nbytes):
+                return np.asarray(out)
         return run
 
     slabs = split_domain(np.asarray(f0), n_servers)
     bufs = [rt.create_buffer(int(s.nbytes)) for s in slabs]
-    evs = [rt.enqueue_write(f"s{i}", b, s)
-           for i, (b, s) in enumerate(zip(bufs, slabs))]
+    evs = [rt.enqueue_write(name, b, s)
+           for name, b, s in zip(names, bufs, slabs)]
     step_seconds = []
     for step in range(steps):
         t0 = time.perf_counter()
         for i, b in enumerate(bufs):
             # simulated device time: one read and one write of the slab
-            k = rt.enqueue_kernel(f"s{i}", fn=kernel(i), inputs=[b],
+            k = rt.enqueue_kernel(names[i], fn=kernel(i), inputs=[b],
                                   outputs=[b], bytes_moved=2 * b.nbytes,
                                   wait_for=[evs[i]], name="lbm_step")
-            rt.enqueue_read(f"s{i}", b, wait_for=[k])
+            rt.enqueue_read(names[i], b, wait_for=[k])
         rt.finish()
         slabs = [b.data for b in bufs]
         if step < steps - 1:
-            evs = [rt.enqueue_write(f"s{i}", b, s) for i, (b, s) in
-                   enumerate(zip(bufs, exchange_halos(slabs)))]
+            evs = [rt.enqueue_write(name, b, s) for name, b, s in
+                   zip(names, bufs, exchange_halos(slabs))]
         step_seconds.append(time.perf_counter() - t0)
-    f = np.concatenate([s[:, :, 1:-1] for s in slabs], axis=2)
+    with span("lbm.concatenate"):
+        f = np.concatenate([s[:, :, 1:-1] for s in slabs], axis=2)
     return OffloadRun(f=f, devices=[sorted(s, key=lambda d: d.id)
                                     for s in seen],
                       step_seconds=step_seconds, stats=rt.stats())

@@ -84,6 +84,7 @@ import secrets
 from collections import deque
 from typing import Callable, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import commands as C
@@ -101,6 +102,7 @@ from repro.core.scheduler import (DeviceScheduler, make_policy,
                                   validate_scheduler_opts)
 from repro.core.store import BufferStore, DIGEST_BYTES, content_digest
 from repro.core import trace as trace_mod
+from repro.core.trace import span
 from repro.core.transport import (make_transport, wire_scale, scale_chunks,
     CLIENT_SUBMIT, CLIENT_REAP, CMD_BYTES, DISPATCH, COMPLETE_WRITE)
 
@@ -624,23 +626,7 @@ class ServerSim:
                     # written — completion is void
                     release()
                     return
-                if isinstance(cmd, C.NDRangeKernel):
-                    if cmd.fn is not None:
-                        ins = [b.data for b in cmd.inputs]
-                        outs = cmd.fn(*ins)
-                        if not isinstance(outs, (tuple, list)):
-                            outs = (outs,)
-                        for b, arr in zip(cmd.outputs, outs):
-                            b.set_data(np.asarray(arr), self.name)
-                    else:
-                        for b in cmd.outputs:
-                            b.invalidate_except(self.name)
-                            b.valid_on = {self.name}
-                else:
-                    for b in getattr(cmd, "outputs", ()):
-                        b.invalidate_except(self.name)
-                        b.valid_on = {self.name}
-                self._complete(ev)
+                self._finish_exec(ev)
                 release()       # device freed: policy picks the next cmd
 
             ev.t_start, _ = dev.execute(cost, done)
@@ -666,7 +652,6 @@ class ServerSim:
         slice; a drain that sweeps a preempted remainder requeues the
         whole command elsewhere via its (event, device) tag, same as
         any queued entry."""
-        cmd = ev.command
         deadline = ev.deadline
         # residual-laxity base: a deadline-less command preempts never
         # and yields always (key inf), matching its queue priority
@@ -719,22 +704,29 @@ class ServerSim:
         sch.submit(self, weight, cost, run, (ev, dname), deadline)
 
     def _finish_exec(self, ev: Event):
-        """Final-slice completion for the preemptible path: write the
-        outputs and complete the event (the non-preemptive path keeps
-        this logic inline in its ``done`` closure)."""
+        """The end of a command's device time, on either path (the last
+        slice of a preemptible one): call the kernel's ``fn`` and commit
+        its outputs to their buffers here, or mark the outputs valid
+        here, then complete the event. The call and the commit are the
+        ``pocl.kernel`` and ``pocl.commit`` spans (DESIGN.md §9); the
+        commit's ``bytes`` are those it copied off a device."""
         cmd = ev.command
-        if isinstance(cmd, C.NDRangeKernel):
-            if cmd.fn is not None:
-                ins = [b.data for b in cmd.inputs]
-                outs = cmd.fn(*ins)
-                if not isinstance(outs, (tuple, list)):
-                    outs = (outs,)
+        fn = cmd.fn if isinstance(cmd, C.NDRangeKernel) else None
+        if fn is not None:
+            ins = [b.data for b in cmd.inputs]
+            with span("pocl.kernel", event=ev.id, server=self.name):
+                outs = fn(*ins)
+            if not isinstance(outs, (tuple, list)):
+                outs = (outs,)
+            with span("pocl.commit", event=ev.id) as sp:
+                copied = 0
                 for b, arr in zip(cmd.outputs, outs):
-                    b.set_data(np.asarray(arr), self.name)
-            else:
-                for b in cmd.outputs:
-                    b.invalidate_except(self.name)
-                    b.valid_on = {self.name}
+                    host = np.asarray(arr)
+                    if isinstance(arr, jax.Array):
+                        copied += host.nbytes
+                    b.set_data(host, self.name)
+                if copied:
+                    sp.set_metadata(bytes=copied)
         else:
             for b in getattr(cmd, "outputs", ()):
                 b.invalidate_except(self.name)
@@ -1339,45 +1331,47 @@ class ClientRuntime:
         engine for this one kernel regardless of policy (used by the
         redundant-dispatch race, whose whole point is landing each copy
         on a DIFFERENT explicitly-chosen server)."""
-        self._check_live()
-        engine = self.cluster.placement
-        if not pin:
-            server = engine.place(self, server, device, inputs, flops,
-                                  bytes_moved, duration)
-        if not self.sessions[server].available:
-            raise DeviceUnavailable(server)
-        deps = list(wait_for)
-        for b in inputs:
-            if server not in b.valid_on:
-                deps.append(self.enqueue_migration(b, server,
-                                                   wait_for=wait_for))
-        # copy-on-write (DESIGN.md §5): writing an output that holds
-        # shared content forks it to a private buffer first — the shared
-        # replicas stay intact for the other holders, and the fork's
-        # device-side copy (read + write of the buffer) is charged to
-        # this kernel's memory traffic (a ``duration`` override absorbs
-        # it, like every other analytic cost term)
-        store = self.cluster.store
-        if store is not None:
+        with span("pocl.enqueue_kernel") as sp:
+            self._check_live()
+            engine = self.cluster.placement
+            if not pin:
+                server = engine.place(self, server, device, inputs, flops,
+                                      bytes_moved, duration)
+            if not self.sessions[server].available:
+                raise DeviceUnavailable(server)
+            deps = list(wait_for)
+            for b in inputs:
+                if server not in b.valid_on:
+                    deps.append(self.enqueue_migration(b, server,
+                                                       wait_for=wait_for))
+            # copy-on-write (DESIGN.md §5): writing an output that holds
+            # shared content forks it to a private buffer first — the shared
+            # replicas stay intact for the other holders, and the fork's
+            # device-side copy (read + write of the buffer) is charged to
+            # this kernel's memory traffic (a ``duration`` override absorbs
+            # it, like every other analytic cost term)
+            store = self.cluster.store
+            if store is not None:
+                for b in outputs:
+                    if store.cow_fork(b):
+                        bytes_moved += 2.0 * b.nbytes
+            cmd = C.NDRangeKernel(fn=fn, inputs=tuple(inputs),
+                                  outputs=tuple(outputs), flops=flops,
+                                  bytes_moved=bytes_moved, duration=duration,
+                                  name=name)
+            ev = self._new_event(cmd, server)
+            sp.set_metadata(event=ev.id)
+            if engine.telemetry_active:
+                engine.record(server,
+                              engine.kernel_cost(server, device, flops,
+                                                 bytes_moved, duration), ev)
+            self._send_command(ev, server, device, [d.id for d in deps])
             for b in outputs:
-                if store.cow_fork(b):
-                    bytes_moved += 2.0 * b.nbytes
-        cmd = C.NDRangeKernel(fn=fn, inputs=tuple(inputs),
-                              outputs=tuple(outputs), flops=flops,
-                              bytes_moved=bytes_moved, duration=duration,
-                              name=name)
-        ev = self._new_event(cmd, server)
-        if engine.telemetry_active:
-            engine.record(server,
-                          engine.kernel_cost(server, device, flops,
-                                             bytes_moved, duration), ev)
-        self._send_command(ev, server, device, [d.id for d in deps])
-        for b in outputs:
-            # eager client-side clobber: later enqueues must neither read
-            # stale replicas nor coalesce onto migrations of the old
-            # contents, so the version bumps at enqueue time too
-            b.invalidate_except(server)
-        return ev
+                # eager client-side clobber: later enqueues must neither read
+                # stale replicas nor coalesce onto migrations of the old
+                # contents, so the version bumps at enqueue time too
+                b.invalidate_except(server)
+            return ev
 
     def enqueue_many(self, server: str, kernels: Sequence[dict],
                      device: str = "", pin: bool = False) -> list:
@@ -1469,21 +1463,23 @@ class ClientRuntime:
 
     def enqueue_write(self, server: str, buf: Buffer, data,
                       wait_for: Sequence[Event] = ()) -> Event:
-        self._check_live()
-        cmd = C.WriteBuffer(buffer=buf, data=data,
-                            nbytes=np.asarray(data).nbytes)
-        ev = self._new_event(cmd, server)
-        dep_ids = [d.id for d in wait_for]
-        store = self.cluster.store
-        if store is not None and cmd.nbytes > 0:
-            self._send_write_via_store(ev, server, buf, cmd, dep_ids,
-                                       store)
-        else:
-            self._send_command(ev, server, "", dep_ids,
-                               payload=cmd.nbytes)
-        buf.valid_on = {server, "client"}
-        buf.version += 1        # eager: new contents are on their way
-        return ev
+        with span("pocl.enqueue_write") as sp:
+            self._check_live()
+            cmd = C.WriteBuffer(buffer=buf, data=data,
+                                nbytes=np.asarray(data).nbytes)
+            ev = self._new_event(cmd, server)
+            sp.set_metadata(event=ev.id)
+            dep_ids = [d.id for d in wait_for]
+            store = self.cluster.store
+            if store is not None and cmd.nbytes > 0:
+                self._send_write_via_store(ev, server, buf, cmd, dep_ids,
+                                           store)
+            else:
+                self._send_command(ev, server, "", dep_ids,
+                                   payload=cmd.nbytes)
+            buf.valid_on = {server, "client"}
+            buf.version += 1        # eager: new contents are on their way
+            return ev
 
     def _record_dedup(self, store: BufferStore, entry, nbytes: float):
         store.record_dedup(entry, nbytes)
@@ -1587,11 +1583,13 @@ class ClientRuntime:
 
     def enqueue_read(self, server: str, buf: Buffer,
                      wait_for: Sequence[Event] = ()) -> Event:
-        self._check_live()
-        cmd = C.ReadBuffer(buffer=buf)
-        ev = self._new_event(cmd, server)
-        self._send_command(ev, server, "", [d.id for d in wait_for])
-        return ev
+        with span("pocl.enqueue_read") as sp:
+            self._check_live()
+            cmd = C.ReadBuffer(buffer=buf)
+            ev = self._new_event(cmd, server)
+            sp.set_metadata(event=ev.id)
+            self._send_command(ev, server, "", [d.id for d in wait_for])
+            return ev
 
     def enqueue_migration(self, buf: Buffer, dst: str,
                           wait_for: Sequence[Event] = ()) -> Event:
@@ -2484,7 +2482,8 @@ class ClientRuntime:
         """Drain the simulation; returns the final clock time. The clock
         is the cluster's, so on a shared cluster this drains every
         attached tenant, not just this one."""
-        return self.clock.run()
+        with span("pocl.finish"):
+            return self.clock.run()
 
     def stats(self) -> dict:
         # NOTE: peer_link_bytes and device_busy read the cluster-shared

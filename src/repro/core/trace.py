@@ -28,6 +28,17 @@ command-latency decomposition. ``MetricsRegistry`` layers windowed
 p50/p95/p99 histograms per tenant/server/device/link on top of the raw
 spans and can flatten ``Cluster.stats()`` counters into the same
 namespace, unifying the ad-hoc scoreboards.
+
+The wall-clock half: ``span(name, **meta)`` marks a stretch of the
+host's real work (a command's enqueue, a kernel call, a host copy) as a
+``jax.profiler.TraceAnnotation``, so it lands in the profiler's own
+trace on the clock the device planes use, and a gap in device activity
+can be put down to what the host was doing in it. The keyword arguments
+become the event's stats. Off — no profiler running — ``span`` is a gate
+check that returns one shared no-op context, so the same rule holds:
+tracing off is free. An operator records the spans with
+``jax.profiler.trace(dir)`` around the work and opens the trace in
+Perfetto or XProf.
 """
 from __future__ import annotations
 
@@ -37,8 +48,10 @@ import math
 from fractions import Fraction
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "CmdRecord", "MetricsRegistry", "Histogram",
-           "set_default", "get_default", "STAGES"]
+           "set_default", "get_default", "span", "STAGES"]
 
 # Lifecycle stages of the latency decomposition, in causal order. Each
 # is the delta between two adjacent stamps of the forward-filled stamp
@@ -62,6 +75,36 @@ def set_default(tracer: Optional["Tracer"]) -> None:
 
 def get_default() -> Optional["Tracer"]:
     return _DEFAULT
+
+
+class _Off:
+    """The span handed out while no profiler runs: enters, exits and
+    takes metadata, and records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **meta):
+        pass
+
+
+_OFF = _Off()
+
+
+def span(name: str, **meta):
+    """A wall-clock span of the host's work, on the profiler's clock:
+    ``jax.profiler.TraceAnnotation(name, **meta)`` while a profiler
+    trace is being recorded, else the shared no-op. Use it as a context
+    manager; ``set_metadata(**meta)`` on what it enters adds stats known
+    only inside the span (an event id, the bytes a copy moved)."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **meta)
+    return _OFF
 
 
 def _round_shares(shares: list, decimals: int = 2) -> list:

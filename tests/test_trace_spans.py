@@ -1,0 +1,174 @@
+"""The wall-clock spans of ``core.trace.span`` (DESIGN.md §9) on the CPU:
+off, they are one shared no-op; under a ``jax.profiler`` trace, the
+runtime's command path and the CFD offload loop record every span,
+nested as documented and with their stats."""
+import numpy as np
+import pytest
+from jax.profiler import ProfileData, trace as profiler_trace
+
+from repro.apps import lbm
+from repro.core import ClientRuntime, DeviceSpec, LinkSpec, ServerSpec
+from repro.core import trace as trace_mod
+from repro.core.runtime import Cluster
+
+PREFIXES = ("pocl.", "lbm.")
+H, W, SERVERS, STEPS = 64, 128, 2, 2
+
+
+def recorded(tmp_path, work) -> list:
+    """``work()`` under a profiler trace; the program spans it recorded,
+    ``(name, start ns, end ns, stats)`` sorted by start."""
+    with profiler_trace(str(tmp_path)):
+        work()
+    files = list(tmp_path.rglob("*.xplane.pb"))
+    assert len(files) == 1
+    out = []
+    for plane in ProfileData.from_file(str(files[0])).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(PREFIXES):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def inside(inner, outers) -> bool:
+    return any(s <= inner[1] and inner[2] <= e for _, s, e, _ in outers)
+
+
+def named(spans, name) -> list:
+    return [sp for sp in spans if sp[0] == name]
+
+
+def chain(rt):
+    """One pass-through chain: write, jitted copy, read, finish."""
+    import jax
+    import jax.numpy as jnp
+    a, b = rt.create_buffer(4), rt.create_buffer(4)
+    copy = jax.jit(jnp.copy)
+    w = rt.enqueue_write("s0", a, np.array([7], np.int32))
+    k = rt.enqueue_kernel("s0", fn=lambda x: copy(jax.device_put(x)),
+                          inputs=[a], outputs=[b], wait_for=[w])
+    rt.enqueue_read("s0", b, wait_for=[k])
+    rt.finish()
+    return b
+
+
+def one_server():
+    return ClientRuntime(servers=[ServerSpec("s0", [DeviceSpec("cpu")])],
+                         client_link=LinkSpec(latency=1e-4,
+                                              bandwidth=1e8))
+
+
+@pytest.fixture(scope="module")
+def f0():
+    return np.asarray(lbm.init_shear(H, W))
+
+
+@pytest.fixture(scope="module")
+def cfd_spans(tmp_path_factory, f0):
+    lbm.run_offloaded(f0, SERVERS, STEPS)        # compiled outside
+    return recorded(tmp_path_factory.mktemp("cfd"),
+                    lambda: lbm.run_offloaded(f0, SERVERS, STEPS))
+
+
+@pytest.fixture(scope="module")
+def chain_spans(tmp_path_factory):
+    chain(one_server())
+    return recorded(tmp_path_factory.mktemp("chain"),
+                    lambda: chain(one_server()))
+
+
+def test_off_span_is_one_shared_noop_and_makes_no_annotation(monkeypatch):
+    made = []
+
+    class Counting(trace_mod.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Counting)
+    assert not Counting.is_enabled()
+    off = trace_mod.span("pocl.x", event=1)
+    assert off is trace_mod.span("lbm.y") and off is trace_mod._OFF
+    with off as sp:
+        sp.set_metadata(bytes=4)
+    b = chain(one_server())
+    lbm.run_offloaded(np.asarray(lbm.init_shear(16, 32)), 2, 2)
+    assert made == []
+    np.testing.assert_array_equal(b.data, [7])
+
+
+def test_cfd_records_every_span_nested_as_documented(cfd_spans):
+    count = {n: len(named(cfd_spans, n)) for n in
+             {sp[0] for sp in cfd_spans}}
+    assert count == {
+        "lbm.split": 1, "lbm.exchange_halos": STEPS - 1,
+        "lbm.concatenate": 1, "pocl.finish": STEPS,
+        "pocl.enqueue_write": SERVERS * STEPS,
+        "pocl.enqueue_kernel": SERVERS * STEPS,
+        "pocl.enqueue_read": SERVERS * STEPS,
+        "pocl.kernel": SERVERS * STEPS, "pocl.commit": SERVERS * STEPS,
+        "lbm.h2d": SERVERS * STEPS, "lbm.d2h": SERVERS * STEPS}
+    finish = named(cfd_spans, "pocl.finish")
+    kernels = named(cfd_spans, "pocl.kernel")
+    assert all(inside(k, finish) for k in kernels)
+    assert all(inside(c, finish) for c in named(cfd_spans, "pocl.commit"))
+    for name in ("lbm.h2d", "lbm.d2h"):
+        assert all(inside(c, kernels) for c in named(cfd_spans, name))
+    for name in ("lbm.split", "lbm.exchange_halos", "lbm.concatenate"):
+        assert not any(inside(sp, finish) for sp in named(cfd_spans, name))
+    assert {sp[3]["server"] for sp in kernels} == {"s0", "s1"}
+    assert all(isinstance(sp[3]["event"], int) for sp in kernels)
+
+
+def test_chain_records_every_command_span(chain_spans):
+    assert [sp[0] for sp in chain_spans] == [
+        "pocl.enqueue_write", "pocl.enqueue_kernel", "pocl.enqueue_read",
+        "pocl.finish", "pocl.kernel", "pocl.commit"]
+    w, k, r, fin, call, commit = chain_spans
+    assert w[3]["event"] < k[3]["event"] < r[3]["event"]
+    assert call[3] == {"event": k[3]["event"], "server": "s0"}
+    assert inside(call, [fin]) and inside(commit, [fin])
+    assert call[2] <= commit[1]
+    # the copy's output was a device array: its four bytes came back
+    assert commit[3] == {"event": k[3]["event"], "bytes": 4}
+
+
+def test_byte_stats_sum_to_the_slab_bytes(cfd_spans, f0):
+    slab = 9 * H * (W // SERVERS + 2) * f0.itemsize
+    copies = named(cfd_spans, "lbm.h2d") + named(cfd_spans, "lbm.d2h")
+    assert all(sp[3]["bytes"] == slab for sp in copies)
+    total = sum(sp[3].get("bytes", 0) for sp in cfd_spans)
+    assert total == 2 * SERVERS * STEPS * slab
+    # the loop's kernels hand back host arrays: the commit copies none
+    assert not any("bytes" in sp[3] for sp in named(cfd_spans,
+                                                    "pocl.commit"))
+
+
+def test_preemptive_policy_calls_each_kernel_once(tmp_path):
+    """Under ``llf`` a kernel runs in slices; its function is called and
+    its outputs committed once, on the last slice."""
+    n = 3
+
+    def work():
+        cluster = Cluster([ServerSpec("s0", [DeviceSpec("gpu0")])],
+                          scheduler="llf", scheduler_opts={"chunk": 1e-4})
+        rt = ClientRuntime(cluster=cluster,
+                           client_link=LinkSpec(latency=1e-4,
+                                                bandwidth=1e8))
+        buf = rt.create_buffer(64)
+        evs = [rt.enqueue_write("s0", buf, np.zeros(16, np.float32))]
+        for _ in range(n):
+            evs.append(rt.enqueue_kernel("s0", fn=lambda x: x + 1.0,
+                                         inputs=[buf], outputs=[buf],
+                                         duration=1e-3,
+                                         wait_for=[evs[-1]]))
+        rt.finish()
+        np.testing.assert_array_equal(buf.data, np.full(16, n, np.float32))
+
+    spans = recorded(tmp_path, work)
+    calls = named(spans, "pocl.kernel")
+    assert len(calls) == n == len(named(spans, "pocl.commit"))
+    assert len({sp[3]["event"] for sp in calls}) == n
