@@ -115,14 +115,22 @@ def reference_max_error(got: np.ndarray, f0: np.ndarray, steps: int,
 # ---------------- domain decomposition ----------------
 
 def split_domain(f: np.ndarray, n: int) -> list:
-    """Split [9,H,W] along W into n slabs, each padded with 1-col halos."""
+    """Split [9,H,W] along W into n slabs, each padded with 1-col periodic
+    halos. Each slab is a fresh C-contiguous array filled by slice copies:
+    the interior in one, then the two halo columns."""
     W_ = f.shape[2]
     if W_ % n:
         raise ValueError(f"width {W_} does not split into {n} slabs")
     w = W_ // n
-    cols = np.arange(-1, w + 1)
+    slabs = []
     with span("lbm.split"):
-        return [np.take(f, (i * w + cols) % W_, axis=2) for i in range(n)]
+        for lo in range(0, W_, w):
+            slab = np.empty(f.shape[:2] + (w + 2,), f.dtype)
+            slab[:, :, 1:-1] = f[:, :, lo:lo + w]
+            slab[:, :, 0] = f[:, :, (lo - 1) % W_]
+            slab[:, :, -1] = f[:, :, (lo + w) % W_]
+            slabs.append(slab)
+    return slabs
 
 
 def exchange_halos(slabs: list) -> list:

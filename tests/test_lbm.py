@@ -56,6 +56,31 @@ def test_offloaded_matches_monolithic(f0, monolithic, n_servers):
     assert run.stats["time"] > 0.0
 
 
+@pytest.mark.parametrize("h_minor", [False, True])
+@pytest.mark.parametrize("shape", [(9, 24, 32), (9, 10, 36)])
+@pytest.mark.parametrize("n", [1, 2, 4])
+def test_split_domain_equals_gather(shape, n, h_minor):
+    """Each slab is the periodic gather of its columns, halos included
+    (for n == 1 both halos wrap around the whole lattice), also from a
+    lattice with H innermost in memory, the order a TPU hands slabs back
+    in."""
+    f = np.random.default_rng(1).standard_normal(shape).astype(np.float32)
+    if h_minor:
+        f = f.transpose(0, 2, 1).copy().transpose(0, 2, 1)
+        assert f.strides[1] == f.itemsize
+    W_ = shape[2]
+    w = W_ // n
+    slabs = lbm.split_domain(f, n)
+    assert len(slabs) == n
+    for i, slab in enumerate(slabs):
+        ref = np.take(f, (i * w + np.arange(-1, w + 1)) % W_, axis=2)
+        assert slab.dtype == ref.dtype and slab.shape == ref.shape
+        assert np.array_equal(slab, ref)
+        assert slab.flags.c_contiguous
+    assert np.array_equal(
+        np.concatenate([s[:, :, 1:-1] for s in slabs], axis=2), f)
+
+
 def test_split_domain_rejects_uneven_width(f0):
     with pytest.raises(ValueError):
         lbm.split_domain(f0, 3)
