@@ -92,6 +92,10 @@ class Deployment:
         lat = config["lattice"]
         self.cells = lat["height"] * lat["width"]
         self.devices = devices
+        # server i on the cell's chip i % chips: the layout the check
+        # holds every job's outputs to
+        self.layout = [devices[i % len(devices)]
+                       for i in range(self.n_servers)]
         self.f = initial_state(config, seed, devices[0])
         # warm up every program a job runs: a one-step job compiles the
         # kernel for each server's device at the slab shapes
@@ -101,10 +105,20 @@ class Deployment:
         self.sample = None          # (f_in, f_out) of the drawn job
         self.job_devices = []       # per job: per server, output devices
 
+    def _offload(self, steps: int):
+        """One ``run_offloaded`` job with its servers on ``self.layout``.
+        The program puts server i on ``jax.local_devices()[i % count]``,
+        so it is shown the cell's chips alone as the local devices: on a
+        host with more chips than the cell was given, that rule then
+        gives the layout too."""
+        import jax
+        with mock.patch.object(jax, "local_devices",
+                               lambda: list(self.devices)):
+            return self.lbm.run_offloaded(self.f, self.n_servers, steps)
+
     def _serve(self, steps: int):
-        run = self.lbm.run_offloaded(self.f, self.n_servers, steps)
+        run = self._offload(steps)
         f_in, self.f = self.f, run.f
-        self.step_seconds = run.step_seconds
         # the job's runtime holds every slab it wrote in reference cycles
         # (some 7 lattices): without a collection per job the host runs
         # out of memory within two or three jobs
@@ -122,15 +136,13 @@ class Deployment:
 
     def describe(self) -> list:
         return [f"cfd: {self.jobs} jobs of {self.steps} steps, "
-                f"{self.n_servers} servers on {len(self.devices)} chips; "
-                f"the last job's steps by the program's own clock (not a "
-                f"metric): {self.step_seconds}"]
+                f"{self.n_servers} servers on chips "
+                f"{[d.id for d in self.layout]}"]
 
     def check(self):
         """The drawn job against the float64 reference, and each job's
         outputs on the devices the layout puts its servers on."""
-        expect = [[self.devices[i % len(self.devices)].id]
-                  for i in range(self.n_servers)]
+        expect = [[d.id] for d in self.layout]
         misplaced = sum(
             [[d.id for d in devs] for devs in job] != expect
             for job in self.job_devices)

@@ -95,7 +95,7 @@ def test_every_file_is_found_by_its_name(spec):
         assert config["reduced"] == c["reduced"]
     for w in spec["workloads"]:
         cell = harness.load_cell(spec, w["name"])
-        assert cell.chips == w["chips"]
+        assert cell.chips == w["chips"] == cell.config["chips"]
         assert hasattr(cell.entry, "Deployment")
     for m in spec["end_to_end"] + spec["per_layer"]:
         mod = harness.load_module(harness.metric_file(m["name"]))

@@ -105,6 +105,30 @@ def test_host_us_per_chain_reader(one):
     assert mod.read(SimpleNamespace(trace=None)) is None
 
 
+def busy_only(busy: dict) -> tracereduce.Reduced:
+    return tracereduce.Reduced(t0=0, t1=1000, busy=busy, ops=[],
+                               modules=[], spans=[], host=[])
+
+
+@pytest.mark.parametrize("busy,expect", [
+    ({0: [(0, 100)], 1: [(0, 100)]}, 2.0),           # the same interval
+    ({0: [(0, 100)], 1: [(50, 100)]}, 1.5),          # over half of chip 0
+    ({0: [(0, 100), (300, 400)]}, 1.0),              # one chip
+    ({d: [(100 * d, 100 * d + 100)] for d in range(4)}, 1.0),  # in turn
+    ({d: [(0, 100)] for d in range(4)}, 4.0),        # all four at once
+])
+def test_chips_busy_at_once_reader(busy, expect):
+    mod = harness.load_module(harness.metric_file("cfd.chips_busy_at_once"))
+    assert mod.read(SimpleNamespace(trace=busy_only(busy))) == expect
+
+
+def test_chips_busy_at_once_reads_nothing_without_busy_time():
+    mod = harness.load_module(harness.metric_file("cfd.chips_busy_at_once"))
+    assert mod.read(SimpleNamespace(trace=None)) is None
+    assert mod.read(SimpleNamespace(trace=busy_only({0: [], 1: []}))) \
+        is None
+
+
 def test_a_trace_without_the_window_is_refused():
     from jax.profiler import ProfileData
     with pytest.raises(ValueError, match="bench.window"):
