@@ -15,6 +15,13 @@ Configs (paper Fig. 15 bars):
 
 Calibration targets: offload ≈2.3×, +DYN ≈19× fps vs igpu_ar; energy per
 frame down to ~6 % (paper: 5.7 %).
+
+The stage times are modeled constants, not measurements: ``T_*_SRV`` are
+the paper's edge server (a GTX 1060) for 860k points, ``T_*_LOCAL`` the
+phone's. The on-chip benchmark's cell ``ar_pointcloud_860k.frame``
+(``benchmarks/chip/``) runs the reconstruct and the depth sort of 860k
+points a frame as real kernels on the chip, through ``ClientRuntime``
+(``repro.apps.ar``); the decode stays a constant here.
 """
 from __future__ import annotations
 

@@ -2,10 +2,11 @@
 
 ``content_size_buffer`` implements the paper's ``cl_pocl_content_size``
 extension (§5.3): a designated 4-byte buffer holds the number of
-meaningful bytes; migrations move only that prefix. The canonical array
-lives host-side in the simulation (all copies are bit-identical); what
-the runtime tracks is *where* valid copies exist and what moving them
-costs.
+meaningful bytes; migrations and reads move only that prefix, and a
+kernel that writes both the buffer and its size buffer leaves only the
+prefix in the buffer. The canonical array lives host-side in the
+simulation (all copies are bit-identical); what the runtime tracks is
+*where* valid copies exist and what moving them costs.
 """
 from __future__ import annotations
 
@@ -24,7 +25,11 @@ class Buffer:
     content_size_buffer: Optional["Buffer"] = None
     name: str = ""
     id: int = dataclasses.field(default_factory=lambda: next(_buf_ids))
-    data: Optional[np.ndarray] = None           # canonical contents
+    # canonical contents: the whole array, or, where the kernel that
+    # wrote it also wrote its content-size buffer, only the leading rows
+    # that hold the used prefix (``transfer_bytes()``, rounded up to a
+    # whole row)
+    data: Optional[np.ndarray] = None
     valid_on: set = dataclasses.field(default_factory=set)  # server names
     registered_mr: set = dataclasses.field(default_factory=set)
     # content generation: bumped on every write/clobber. The runtime's

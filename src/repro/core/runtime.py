@@ -12,7 +12,9 @@ Semantics implemented faithfully:
 * Buffer migrations go source-server → destination-server directly over
   peer links (§5.1); ``p2p_migration=False`` stages them through the
   client (the naive path: download + upload over the slowest link).
-* ``cl_pocl_content_size`` (§5.3): migrations move only the used prefix.
+* ``cl_pocl_content_size`` (§5.3): migrations and reads move only the
+  used prefix, and a kernel that also writes an output's content-size
+  buffer leaves only that prefix in the output's buffer.
 * TCP vs RDMA transports (§5.4) with shadow-buffer staging, registration
   and rkey-exchange costs.
 * Connection loss (§4.3): session IDs, command replay on reconnect,
@@ -111,6 +113,14 @@ log = logging.getLogger(__name__)
 # residual-laxity base for deadline-less commands under a preemptive
 # scheduler: never tighter than anything, so they always yield
 _INF = float("inf")
+
+
+def _used_rows(host: np.ndarray, nbytes: float) -> np.ndarray:
+    """The leading rows of ``host`` that hold its first ``nbytes`` bytes
+    (a part row counts as a row)."""
+    if host.nbytes == 0:
+        return host
+    return host[:-(-int(nbytes) * host.shape[0] // host.nbytes)]
 
 
 @dataclasses.dataclass
@@ -709,7 +719,13 @@ class ServerSim:
         its outputs to their buffers here, or mark the outputs valid
         here, then complete the event. The call and the commit are the
         ``pocl.kernel`` and ``pocl.commit`` spans (DESIGN.md §9); the
-        commit's ``bytes`` are those it copied off a device."""
+        commit's ``bytes`` are those it copied off a device. An output
+        whose content-size buffer is among the same kernel's outputs is
+        committed after that buffer and keeps only the rows of its used
+        prefix (``Buffer.transfer_bytes()``). It still leaves the device
+        in one whole copy: on a TPU v5e host that is quicker than copying
+        only the prefix, in pieces or after a slicing program (PERF.md
+        §5)."""
         cmd = ev.command
         fn = cmd.fn if isinstance(cmd, C.NDRangeKernel) else None
         if fn is not None:
@@ -720,11 +736,25 @@ class ServerSim:
                 outs = (outs,)
             with span("pocl.commit", event=ev.id) as sp:
                 copied = 0
+                sized = []
                 for b, arr in zip(cmd.outputs, outs):
+                    csb = b.content_size_buffer
+                    if csb is not None and np.ndim(arr) > 0 and any(
+                            csb is o for o in cmd.outputs):
+                        # its size comes from this kernel too: commit
+                        # it first, then only the used prefix
+                        sized.append((b, arr))
+                        continue
                     host = np.asarray(arr)
                     if isinstance(arr, jax.Array):
                         copied += host.nbytes
                     b.set_data(host, self.name)
+                for b, arr in sized:
+                    host = np.asarray(arr)
+                    if isinstance(arr, jax.Array):
+                        copied += host.nbytes
+                    b.set_data(_used_rows(host, b.transfer_bytes()),
+                               self.name)
                 if copied:
                     sp.set_metadata(bytes=copied)
         else:

@@ -1,8 +1,8 @@
-"""Compile the CFD kernel of ``chip_smoke.py`` for a TPU v5e that is
-described, not attached. This catches what the chip's compiler refuses, a
-lattice that does not fit the chip's HBM, and a bf16 copy of the
-distribution (the TPU lowering of a DEFAULT-precision f32 contraction)
-without a chip.
+"""Compile the CFD kernel of ``chip_smoke.py``, and the AR cell's
+reconstruct kernel, for a TPU v5e that is described, not attached. This
+catches what the chip's compiler refuses, a lattice that does not fit
+the chip's HBM, and a bf16 copy of the distribution (the TPU lowering of
+a DEFAULT-precision f32 contraction) without a chip.
 
 The topology is described inside a fixture: only one process at a time
 may load the TPU compiler's library, so no module may touch it while it is
@@ -62,3 +62,28 @@ def test_lbm_step_compiles_for_v5e_in_f32(width, one_chip,
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < HBM_BYTES
+
+
+AR_POINTS = 860_000             # the AR cell's frame capacity
+
+
+def test_ar_reconstruct_compiles_for_v5e_in_f32(one_chip,
+                                                no_persistent_cache):
+    """The pose transform stays float32 (no bf16 operand), unpacking the
+    frame's words needs no padded copy, and the frame's argument lies on
+    the device as the host holds it (plain 32-bit words, no tiling the
+    host would have to rearrange it into). (``ar_sort`` is left out: its
+    860k-key sort takes the compiler some 80 s.)"""
+    from repro.apps import ar
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = ar.ar_reconstruct.lower(
+        arg((ar.PARAMS,), jnp.float32), arg((1,), jnp.uint32),
+        arg((3 * AR_POINTS // 2,), jnp.uint32)).compile()
+    assert "bf16" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
+    layout = compiled.input_formats[0][2].layout
+    assert layout.major_to_minor == (0,)
+    assert layout.tiling == ((1024,),)
+

@@ -172,3 +172,78 @@ def test_preemptive_policy_calls_each_kernel_once(tmp_path):
     calls = named(spans, "pocl.kernel")
     assert len(calls) == n == len(named(spans, "pocl.commit"))
     assert len({sp[3]["event"] for sp in calls}) == n
+
+
+ROWS = 1000     # rows of a content-sized uint32 output
+
+
+def sized_chain(rt, used: int, size_from: str):
+    """A kernel writes ``ROWS`` uint32 to ``out`` and ``used`` to a size
+    buffer; ``size_from`` says whose content size that is: ``kernel``
+    (``out``'s, written by the same kernel), ``client`` (``out``'s,
+    written by the client before the kernel) or ``none`` (no buffer
+    names it). ``out`` is read back."""
+    import jax
+    import jax.numpy as jnp
+    size = rt.create_buffer(4)
+    out = rt.create_buffer(ROWS * 4, content_size_buffer=None
+                           if size_from == "none" else size)
+    src = rt.create_buffer(4)
+    fill = jax.jit(lambda u: (jnp.arange(ROWS, dtype=jnp.uint32), u))
+    deps = [rt.enqueue_write("s0", src, np.array([used], np.int32))]
+    outs = [out, size]
+    if size_from == "client":
+        deps.append(rt.enqueue_write("s0", size, np.array([used], np.int32)))
+        outs = [out]
+    k = rt.enqueue_kernel(
+        "s0", fn=lambda u: fill(jax.device_put(u))[:len(outs)],
+        inputs=[src], outputs=outs, wait_for=deps)
+    rt.enqueue_read("s0", out, wait_for=[k])
+    rt.finish()
+    return out
+
+
+@pytest.mark.parametrize("used,size_from,rows,copied", [
+    (400, "kernel", 100, ROWS * 4 + 4),         # keeps 100 of 1000 rows
+    (401, "kernel", 101, ROWS * 4 + 4),         # a part row is a row
+    (4000, "kernel", ROWS, ROWS * 4 + 4),
+    (0, "kernel", 0, ROWS * 4 + 4),
+    (-5, "kernel", 0, ROWS * 4 + 4),            # corrupt: clamped to 0
+    (10**6, "kernel", ROWS, ROWS * 4 + 4),      # corrupt: to nbytes
+    (400, "client", ROWS, ROWS * 4),            # not this kernel's size
+    (400, "none", ROWS, ROWS * 4 + 4),          # no content size
+])
+def test_commit_copies_the_used_prefix(tmp_path, used, size_from, rows,
+                                       copied):
+    """The buffer keeps the rows of its used prefix where the kernel
+    wrote its size too; the copy off the device is the whole output."""
+    sized_chain(one_server(), used, size_from)      # compiled outside
+    got = []
+    spans = recorded(tmp_path, lambda: got.append(
+        sized_chain(one_server(), used, size_from)))
+    out = got[0]
+    np.testing.assert_array_equal(out.data, np.arange(rows,
+                                                      dtype=np.uint32))
+    (commit,) = named(spans, "pocl.commit")
+    assert commit[3]["bytes"] == copied
+    if size_from != "none":
+        assert out.transfer_bytes() == min(max(used, 0), ROWS * 4)
+
+
+def test_host_kernel_commits_the_used_prefix_and_copies_nothing(tmp_path):
+    """A kernel whose outputs are host arrays keeps only the used rows
+    of a content-sized output, and its commit copies nothing off a
+    device."""
+    def work():
+        rt = one_server()
+        size = rt.create_buffer(4)
+        out = rt.create_buffer(ROWS * 4, content_size_buffer=size)
+        k = rt.enqueue_kernel("s0", fn=lambda: (
+            np.arange(ROWS, dtype=np.uint32), np.array([402], np.int32)),
+            outputs=[out, size])
+        rt.enqueue_read("s0", out, wait_for=[k])
+        rt.finish()
+        np.testing.assert_array_equal(out.data, np.arange(101))
+
+    (commit,) = named(recorded(tmp_path, work), "pocl.commit")
+    assert "bytes" not in commit[3]
