@@ -23,13 +23,15 @@ point.
    keys;
 4. ``enqueue_kernel`` ``ar_sort`` → ``uint32[capacity]`` indices of the
    visible points back to front, and their content-size buffer (visible
-   × 4 bytes), so the commit copies only the used prefix off the device;
-5. ``enqueue_read`` of the indices, then ``finish()``.
+   × 4 bytes), so the client gets only the used prefix;
+5. ``enqueue_read`` of the indices, then ``finish()``: the read copies
+   the size and then the indices off the device.
 
 Each kernel's upload to the device is an ``ar.h2d`` span with its
-``bytes`` and ``server``. The two kernels stay two commands, as in the
-paper's pipeline, and the keys between them go through the host at the
-commit.
+``bytes`` (of the arrays that were on the host) and ``server``. The two
+kernels stay two commands, as in the paper's pipeline, and the keys
+between them stay on the chip: the commit keeps the reconstruct's device
+array, and the sort's upload of it is a no-op.
 
 ``reference_frame`` is the plain float64 numpy reference. Departures
 from the paper: the stream's HEVC decode is left out (the frame arrives
@@ -171,7 +173,8 @@ class FrameOffload:
 
     def _upload(self, *arrays):
         with span("ar.h2d", server=self.server,
-                  bytes=sum(a.nbytes for a in arrays)):
+                  bytes=sum(a.nbytes for a in arrays
+                            if isinstance(a, np.ndarray))):
             return jax.device_put(arrays, self.device)
 
     def _reconstruct(self, pose, size, geom):

@@ -115,14 +115,6 @@ log = logging.getLogger(__name__)
 _INF = float("inf")
 
 
-def _used_rows(host: np.ndarray, nbytes: float) -> np.ndarray:
-    """The leading rows of ``host`` that hold its first ``nbytes`` bytes
-    (a part row counts as a row)."""
-    if host.nbytes == 0:
-        return host
-    return host[:-(-int(nbytes) * host.shape[0] // host.nbytes)]
-
-
 @dataclasses.dataclass
 class DeviceSpec:
     name: str
@@ -718,45 +710,38 @@ class ServerSim:
         slice of a preemptible one): call the kernel's ``fn`` and commit
         its outputs to their buffers here, or mark the outputs valid
         here, then complete the event. The call and the commit are the
-        ``pocl.kernel`` and ``pocl.commit`` spans (DESIGN.md §9); the
-        commit's ``bytes`` are those it copied off a device. An output
-        whose content-size buffer is among the same kernel's outputs is
-        committed after that buffer and keeps only the rows of its used
-        prefix (``Buffer.transfer_bytes()``). It still leaves the device
-        in one whole copy: on a TPU v5e host that is quicker than copying
+        ``pocl.kernel`` and ``pocl.commit`` spans (DESIGN.md §9). The
+        commit copies nothing: an output that comes back as a
+        ``jax.Array`` stays on its device, and a host output stays as it
+        was returned, until something needs host bytes (``Buffer.host()``):
+        a read (the ``pocl.read`` span), a content size, a host consumer.
+        A kernel that takes a device output as input gets the device
+        array itself. An output whose content-size buffer is among the
+        same kernel's outputs is cut to the rows of its used prefix when
+        it is made host bytes; a device array still leaves its device in
+        one whole copy, which on a TPU v5e host is quicker than copying
         only the prefix, in pieces or after a slicing program (PERF.md
         §5)."""
         cmd = ev.command
         fn = cmd.fn if isinstance(cmd, C.NDRangeKernel) else None
         if fn is not None:
-            ins = [b.data for b in cmd.inputs]
+            # a content-sized input waiting for its cut is cut first, so
+            # a kernel sees the same rows whatever wrote its input
+            ins = [b.host() if b.cut else b.data for b in cmd.inputs]
             with span("pocl.kernel", event=ev.id, server=self.name):
                 outs = fn(*ins)
             if not isinstance(outs, (tuple, list)):
                 outs = (outs,)
-            with span("pocl.commit", event=ev.id) as sp:
-                copied = 0
-                sized = []
+            with span("pocl.commit", event=ev.id):
                 for b, arr in zip(cmd.outputs, outs):
+                    if not isinstance(arr, jax.Array):
+                        arr = np.asarray(arr)
                     csb = b.content_size_buffer
-                    if csb is not None and np.ndim(arr) > 0 and any(
-                            csb is o for o in cmd.outputs):
-                        # its size comes from this kernel too: commit
-                        # it first, then only the used prefix
-                        sized.append((b, arr))
-                        continue
-                    host = np.asarray(arr)
-                    if isinstance(arr, jax.Array):
-                        copied += host.nbytes
-                    b.set_data(host, self.name)
-                for b, arr in sized:
-                    host = np.asarray(arr)
-                    if isinstance(arr, jax.Array):
-                        copied += host.nbytes
-                    b.set_data(_used_rows(host, b.transfer_bytes()),
-                               self.name)
-                if copied:
-                    sp.set_metadata(bytes=copied)
+                    # its size comes from this kernel too: cut to its
+                    # used prefix when made host bytes
+                    b.set_data(arr, self.name, cut=csb is not None
+                               and arr.ndim > 0
+                               and any(csb is o for o in cmd.outputs))
         else:
             for b in getattr(cmd, "outputs", ()):
                 b.invalidate_except(self.name)
@@ -1704,7 +1689,7 @@ class ClientRuntime:
             srcs = sorted({*srcs, *(s for s in sentry.valid_on
                                     if alive(s))})
         if not srcs:  # client-held data: plain upload
-            return self.enqueue_write(dst, buf, buf.data
+            return self.enqueue_write(dst, buf, buf.host()
                                       if buf.data is not None
                                       else np.zeros(buf.nbytes, np.uint8))
         src = self._pick_migration_source(buf, srcs, dst)
@@ -2151,7 +2136,14 @@ class ClientRuntime:
             store.replica_landed(sentry, dst)
 
     def _start_read_return(self, srv: ServerSim, ev: Event):
+        """The return leg of a read. The buffer is made host bytes first,
+        its content-size buffer before it: the ``pocl.read`` span, whose
+        ``bytes`` are those it copied off a device."""
         buf = ev.command.buffer
+        with span("pocl.read", event=ev.id) as sp:
+            copied = buf.to_host()
+            if copied:
+                sp.set_metadata(bytes=copied)
         nbytes = buf.transfer_bytes()
         ev.data_version = buf.version   # generation of the returned bytes
         cost = self.transport.command_cost(nbytes)
@@ -2453,7 +2445,7 @@ class ClientRuntime:
             if race.status != COMPLETE:
                 # winner executes the functional payload; losers are void
                 if fn is not None:
-                    ins = [b.data for b in kw.get("inputs", ())]
+                    ins = [b.host() for b in kw.get("inputs", ())]
                     outs = fn(*ins)
                     if not isinstance(outs, (tuple, list)):
                         outs = (outs,)
@@ -2493,7 +2485,7 @@ class ClientRuntime:
         def done():
             cmd = ev.command
             if cmd.fn is not None:
-                ins = [b.data for b in cmd.inputs]
+                ins = [b.host() for b in cmd.inputs]
                 outs = cmd.fn(*ins)
                 if not isinstance(outs, (tuple, list)):
                     outs = (outs,)

@@ -110,11 +110,13 @@ def test_cfd_records_every_span_nested_as_documented(cfd_spans):
         "pocl.enqueue_kernel": SERVERS * STEPS,
         "pocl.enqueue_read": SERVERS * STEPS,
         "pocl.kernel": SERVERS * STEPS, "pocl.commit": SERVERS * STEPS,
+        "pocl.read": SERVERS * STEPS,
         "lbm.h2d": SERVERS * STEPS, "lbm.d2h": SERVERS * STEPS}
     finish = named(cfd_spans, "pocl.finish")
     kernels = named(cfd_spans, "pocl.kernel")
     assert all(inside(k, finish) for k in kernels)
-    assert all(inside(c, finish) for c in named(cfd_spans, "pocl.commit"))
+    for name in ("pocl.commit", "pocl.read"):
+        assert all(inside(c, finish) for c in named(cfd_spans, name))
     for name in ("lbm.h2d", "lbm.d2h"):
         assert all(inside(c, kernels) for c in named(cfd_spans, name))
     for name in ("lbm.split", "lbm.exchange_halos", "lbm.concatenate"):
@@ -126,14 +128,17 @@ def test_cfd_records_every_span_nested_as_documented(cfd_spans):
 def test_chain_records_every_command_span(chain_spans):
     assert [sp[0] for sp in chain_spans] == [
         "pocl.enqueue_write", "pocl.enqueue_kernel", "pocl.enqueue_read",
-        "pocl.finish", "pocl.kernel", "pocl.commit"]
-    w, k, r, fin, call, commit = chain_spans
+        "pocl.finish", "pocl.kernel", "pocl.commit", "pocl.read"]
+    w, k, r, fin, call, commit, read = chain_spans
     assert w[3]["event"] < k[3]["event"] < r[3]["event"]
     assert call[3] == {"event": k[3]["event"], "server": "s0"}
     assert inside(call, [fin]) and inside(commit, [fin])
-    assert call[2] <= commit[1]
-    # the copy's output was a device array: its four bytes came back
-    assert commit[3] == {"event": k[3]["event"], "bytes": 4}
+    assert inside(read, [fin])
+    assert call[2] <= commit[1] and commit[2] <= read[1]
+    # the copy's output was a device array: the commit kept it there,
+    # and its four bytes came back at the read
+    assert commit[3] == {"event": k[3]["event"]}
+    assert read[3] == {"event": r[3]["event"], "bytes": 4}
 
 
 def test_byte_stats_sum_to_the_slab_bytes(cfd_spans, f0):
@@ -144,7 +149,8 @@ def test_byte_stats_sum_to_the_slab_bytes(cfd_spans, f0):
     assert total == 2 * SERVERS * STEPS * slab
     # the loop's kernels hand back host arrays: the commit copies none
     assert not any("bytes" in sp[3] for sp in named(cfd_spans,
-                                                    "pocl.commit"))
+                                                    "pocl.commit")
+                   + named(cfd_spans, "pocl.read"))
 
 
 def test_preemptive_policy_calls_each_kernel_once(tmp_path):
@@ -211,29 +217,34 @@ def sized_chain(rt, used: int, size_from: str):
     (-5, "kernel", 0, ROWS * 4 + 4),            # corrupt: clamped to 0
     (10**6, "kernel", ROWS, ROWS * 4 + 4),      # corrupt: to nbytes
     (400, "client", ROWS, ROWS * 4),            # not this kernel's size
-    (400, "none", ROWS, ROWS * 4 + 4),          # no content size
+    (400, "none", ROWS, ROWS * 4),              # no content size
 ])
 def test_commit_copies_the_used_prefix(tmp_path, used, size_from, rows,
                                        copied):
     """The buffer keeps the rows of its used prefix where the kernel
-    wrote its size too; the copy off the device is the whole output."""
+    wrote its size too. The commit leaves both outputs on the device;
+    the read copies the size, where it is the buffer's, and then the
+    whole output off the device."""
     sized_chain(one_server(), used, size_from)      # compiled outside
     got = []
     spans = recorded(tmp_path, lambda: got.append(
         sized_chain(one_server(), used, size_from)))
     out = got[0]
+    assert isinstance(out.data, np.ndarray)
     np.testing.assert_array_equal(out.data, np.arange(rows,
                                                       dtype=np.uint32))
     (commit,) = named(spans, "pocl.commit")
-    assert commit[3]["bytes"] == copied
+    assert "bytes" not in commit[3]
+    (read,) = named(spans, "pocl.read")
+    assert read[3]["bytes"] == copied
     if size_from != "none":
         assert out.transfer_bytes() == min(max(used, 0), ROWS * 4)
 
 
 def test_host_kernel_commits_the_used_prefix_and_copies_nothing(tmp_path):
     """A kernel whose outputs are host arrays keeps only the used rows
-    of a content-sized output, and its commit copies nothing off a
-    device."""
+    of a content-sized output, and neither its commit nor the read
+    copies anything off a device."""
     def work():
         rt = one_server()
         size = rt.create_buffer(4)
@@ -245,5 +256,123 @@ def test_host_kernel_commits_the_used_prefix_and_copies_nothing(tmp_path):
         rt.finish()
         np.testing.assert_array_equal(out.data, np.arange(101))
 
-    (commit,) = named(recorded(tmp_path, work), "pocl.commit")
+    spans = recorded(tmp_path, work)
+    (commit,) = named(spans, "pocl.commit")
     assert "bytes" not in commit[3]
+    (read,) = named(spans, "pocl.read")
+    assert "bytes" not in read[3]
+
+
+def test_device_output_stays_on_its_device_until_read(tmp_path):
+    """A jitted kernel's output is committed as the device array it is,
+    with no copy; a second kernel that takes the buffer gets that very
+    array; nothing leaves the device until the read."""
+    import jax
+    import jax.numpy as jnp
+    double = jax.jit(lambda x: 2 * x)
+    seen = {}
+
+    def work():
+        rt = one_server()
+        a, b, c = (rt.create_buffer(16) for _ in range(3))
+        w = rt.enqueue_write("s0", a, np.arange(4, dtype=np.int32))
+        k1 = rt.enqueue_kernel("s0", fn=lambda x: double(jnp.asarray(x)),
+                               inputs=[a], outputs=[b], wait_for=[w])
+        k1.on_complete(lambda _e: seen.setdefault("committed", b.data))
+
+        def consume(x):
+            seen["input"] = x
+            return double(x)
+        k2 = rt.enqueue_kernel("s0", fn=consume, inputs=[b], outputs=[c],
+                               wait_for=[k1])
+        rt.finish()
+        seen["before_read"] = (b.data, c.data)
+        rt.enqueue_read("s0", c, wait_for=[k2])
+        rt.finish()
+        seen["after_read"] = (b.data, c.data)
+
+    spans = recorded(tmp_path, work)
+    assert isinstance(seen["committed"], jax.Array)
+    assert seen["input"] is seen["committed"]
+    assert all(isinstance(d, jax.Array) for d in seen["before_read"])
+    b_data, c_data = seen["after_read"]
+    assert b_data is seen["committed"]
+    assert isinstance(c_data, np.ndarray)
+    np.testing.assert_array_equal(c_data, [0, 4, 8, 12])
+    assert len(named(spans, "pocl.commit")) == 2
+    assert not any("bytes" in sp[3] for sp in named(spans, "pocl.commit"))
+    (read,) = named(spans, "pocl.read")
+    assert read[3]["bytes"] == 16
+
+
+def test_numpy_output_is_committed_as_returned(tmp_path):
+    """A numpy kernel's output is the buffer's contents from its commit
+    on, the very array it returned, and its read copies nothing."""
+    made = []
+
+    def kernel():
+        made.append(np.arange(4, dtype=np.int32))
+        return made[-1]
+
+    def work():
+        rt = one_server()
+        out = rt.create_buffer(16)
+        k = rt.enqueue_kernel("s0", fn=kernel, outputs=[out])
+        k.on_complete(lambda _e: made.append(out.data))
+        rt.enqueue_read("s0", out, wait_for=[k])
+        rt.finish()
+        made.append(out.data)
+
+    spans = recorded(tmp_path, work)
+    returned, committed, read_back = made
+    assert committed is returned and read_back is returned
+    (commit,), (read,) = named(spans, "pocl.commit"), named(spans,
+                                                           "pocl.read")
+    assert "bytes" not in commit[3] and "bytes" not in read[3]
+
+
+@pytest.mark.parametrize("sized", [False, True])
+def test_host_bytes_keep_version_and_validity(sized):
+    """``Buffer.to_host()`` changes the representation, not the content:
+    a content-sized output is cut to its used rows there, and neither it
+    nor its size buffer changes ``version`` or ``valid_on``."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.buffers import Buffer
+    size = Buffer(4)
+    size.set_data(jnp.array([12], jnp.int32), "s0")
+    buf = Buffer(40, content_size_buffer=size if sized else None)
+    buf.set_data(jnp.arange(10, dtype=jnp.uint32), "s0", cut=sized)
+    before = [(b.version, set(b.valid_on)) for b in (buf, size)]
+    # the whole output off its device, and its size first where it is
+    # the buffer's
+    assert buf.to_host() == 40 + (4 if sized else 0)
+    host = buf.data
+    assert isinstance(host, np.ndarray)
+    np.testing.assert_array_equal(host, np.arange(3 if sized else 10))
+    assert isinstance(size.data, np.ndarray if sized else jax.Array)
+    assert [(b.version, b.valid_on) for b in (buf, size)] == before
+    assert buf.host() is host and not buf.cut and buf.to_host() == 0
+
+
+def test_kernel_input_waiting_for_its_cut_gets_the_used_rows():
+    """A content-sized device output that a second kernel takes as input
+    is cut first: the kernel sees the rows it would see had the first
+    kernel handed back host arrays."""
+    import jax
+    import jax.numpy as jnp
+    fill = jax.jit(lambda: (jnp.arange(ROWS, dtype=jnp.uint32),
+                            jnp.array([400], jnp.int32)))
+    seen = []
+    rt = one_server()
+    size = rt.create_buffer(4)
+    out = rt.create_buffer(ROWS * 4, content_size_buffer=size)
+    total = rt.create_buffer(8)
+    k = rt.enqueue_kernel("s0", fn=fill, outputs=[out, size])
+    rt.enqueue_kernel("s0", fn=lambda x: seen.append(x) or np.sum(x),
+                      inputs=[out], outputs=[total], wait_for=[k])
+    rt.finish()
+    (x,) = seen
+    assert isinstance(x, np.ndarray)
+    np.testing.assert_array_equal(x, np.arange(100, dtype=np.uint32))
+    assert int(total.data) == sum(range(100))
